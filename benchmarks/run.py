@@ -15,12 +15,14 @@ def main() -> None:
                             bench_resilience, bench_serve, bench_thresholds,
                             bench_trace, bench_tradeoff, bench_training)
     from benchmarks.common import Rows
+    from repro.runtime.compile_cache import enable_compile_cache
 
     parser = argparse.ArgumentParser()
     parser.add_argument("--json", nargs="?", const="BENCH.json",
                         default=None, metavar="PATH",
                         help="also write all rows as JSON")
     args = parser.parse_args()
+    enable_compile_cache()
 
     suites = [
         ("cascade (Fig4+Table2)", bench_cascade.run),

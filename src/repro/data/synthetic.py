@@ -53,13 +53,22 @@ def make_corpus(seed: int, n_docs: int = 10_000, dim: int = 256,
     e /= np.linalg.norm(e, axis=1, keepdims=True)
     tokens = None
     if with_tokens:
-        # topic-dependent unigram tables
+        # topic-dependent unigram tables; inverse-CDF sampling by binary
+        # search, a block of documents at a time, so memory stays
+        # O(block * vocab) even at a full LM vocabulary
         tables = rng.dirichlet(np.full(vocab, 0.05), size=n_topics)
-        probs = w @ tables
-        probs /= probs.sum(axis=1, keepdims=True)
-        cdf = np.cumsum(probs, axis=1)
         u = rng.random((n_docs, doc_len))
-        tokens = (u[..., None] < cdf[:, None, :]).argmax(-1).astype(np.int32)
+        tokens = np.empty((n_docs, doc_len), np.int32)
+        block = 256
+        for lo in range(0, n_docs, block):
+            probs = w[lo:lo + block] @ tables
+            probs /= probs.sum(axis=1, keepdims=True)
+            cdf = np.cumsum(probs, axis=1)
+            for i, row in enumerate(cdf):
+                tokens[lo + i] = np.searchsorted(row, u[lo + i],
+                                                 side="right")
+        # a draw above the last CDF entry (rounding) maps to token 0
+        tokens[tokens == vocab] = 0
     return Corpus(embeds=e, topic_weights=w, topics=topics, tokens=tokens)
 
 

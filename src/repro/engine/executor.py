@@ -28,9 +28,9 @@ Three compute paths, chosen per call:
     repro.sharding's logical "batch" rule (so a pod×data mesh shards
     rows over both axes without executor changes). Purely
     data-parallel — no collectives — and it degrades transparently to
-    the single-device path when the mesh has one device.
-    (``use_kernel`` currently wins over ``mesh``: the fused-kernel path
-    runs single-device and the stats say so.)
+    the single-device path when the mesh has one device. The fused
+    kernel runs on one device only, so ``use_kernel=True`` with a
+    multi-device mesh is refused at construction.
 
 Every pass returns a ``ScoringStats`` record (bytes streamed, tiles
 scored, per-stage wall-clock) which the engine aggregates into
@@ -47,7 +47,6 @@ from typing import Dict, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core.scoring import (_iter_chunks, _num_docs,
@@ -222,6 +221,11 @@ class ScoringExecutor:
     def __init__(self, *, chunk: int = 8192, use_kernel: bool = False,
                  interpret: bool = False, mesh: Optional[Mesh] = None,
                  prefetch_depth: int = DEFAULT_PREFETCH_DEPTH):
+        if use_kernel and mesh is not None and mesh.devices.size > 1:
+            raise ValueError(
+                "use_kernel=True scores on one device, but the mesh has "
+                f"{mesh.devices.size}; pass use_kernel=False to shard "
+                "tiles over the mesh, or mesh=None for the fused kernel")
         self.chunk = chunk
         self.use_kernel = use_kernel
         self.interpret = interpret
@@ -266,14 +270,16 @@ class ScoringExecutor:
         tile2d, out2d, out1d = P(row, None), P(row, None), P(row)
 
         if kind == "proxy_multi":
-            mapped = shard_map(_proxy_chunk_scores_impl, mesh=mesh,
-                               in_specs=(P(), tile2d, P()), out_specs=out2d)
+            mapped = jax.shard_map(_proxy_chunk_scores_impl, mesh=mesh,
+                                   in_specs=(P(), tile2d, P()),
+                                   out_specs=out2d)
         elif kind == "raw_multi":
-            mapped = shard_map(_raw_chunk_scores_impl, mesh=mesh,
-                               in_specs=(tile2d, P()), out_specs=out2d)
+            mapped = jax.shard_map(_raw_chunk_scores_impl, mesh=mesh,
+                                   in_specs=(tile2d, P()), out_specs=out2d)
         else:  # single
-            mapped = shard_map(_single_chunk_scores_impl, mesh=mesh,
-                               in_specs=(P(), tile2d, P()), out_specs=out1d)
+            mapped = jax.shard_map(_single_chunk_scores_impl, mesh=mesh,
+                                   in_specs=(P(), tile2d, P()),
+                                   out_specs=out1d)
         fn = jax.jit(mapped)
         self._sharded_fns[kind] = fn
         return fn
@@ -352,7 +358,7 @@ class ScoringExecutor:
         # are (Q_g, latent) for the kernel path, transposed for matmul
         groups, zq_stacks = group_jobs(jobs)
 
-        sharded = self._mesh_size > 1 and not self.use_kernel
+        sharded = self._mesh_size > 1
         pre = _Prefetcher(store, self.chunk, self.prefetch_depth,
                           self._put(sharded))
         out = np.empty((n, len(jobs)), np.float32)

@@ -15,6 +15,7 @@ import numpy as np
 from repro.config import get_arch, get_smoke_arch
 from repro.data import make_corpus
 from repro.models import build_model
+from repro.runtime.compile_cache import enable_compile_cache
 from repro.runtime.serve_loop import EmbeddingService, ServeStats
 
 
@@ -27,6 +28,7 @@ def main():
     ap.add_argument("--doc-len", type=int, default=48)
     ap.add_argument("--out", default="")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_smoke_arch(args.arch) if args.smoke else get_arch(args.arch)
     corpus = make_corpus(0, n_docs=args.docs, dim=128, with_tokens=True,

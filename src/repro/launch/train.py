@@ -13,6 +13,7 @@ import argparse
 from repro.config import (SHAPES_BY_NAME, InputShape, OptimizerConfig,
                           TrainConfig, get_arch, get_smoke_arch)
 from repro.launch.mesh import make_production_mesh, make_test_mesh
+from repro.runtime.compile_cache import enable_compile_cache
 from repro.runtime.train_loop import Trainer
 
 
@@ -31,6 +32,7 @@ def main():
     ap.add_argument("--compress-grads", action="store_true")
     ap.add_argument("--resume", action="store_true")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.smoke:
         cfg = get_smoke_arch(args.arch)

@@ -3,9 +3,12 @@
 Three execution strategies:
   * ``einsum``  — reference quadratic attention (smoke tests, small seqs).
   * ``blocked`` — pure-XLA online-softmax over KV blocks (flash-equivalent
-    FLOPs, O(block^2) memory). Default for prefill/train at scale.
-  * on real TPU, ops-level dispatch swaps in the Pallas flash kernel
-    (repro.kernels.flash_attention) — see models/model.py.
+    FLOPs, O(block^2) memory). ``build_model``'s default on every
+    backend, TPU included.
+  * ``flash``   — the same online softmax with a recompute-in-backward
+    custom VJP (pure jnp; launch/steps.py picks it for training).
+  The Pallas flash kernel (repro.kernels.flash_attention) is not wired
+  into any of them.
 
 Decode uses a KV cache: full caches for global layers, ring buffers for
 sliding-window layers.
