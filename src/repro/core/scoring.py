@@ -67,6 +67,7 @@ def score_collection(params: Dict, e_q: jnp.ndarray, embeds,
     return np.concatenate(outs).astype(np.float32)
 
 
+@jax.named_scope("proxy_score_chunk")
 def _single_chunk_scores_impl(params, block, z_q):
     """block: (B, D); z_q: (latent,) normalized query latent.
 
@@ -83,12 +84,14 @@ def _single_chunk_scores_impl(params, block, z_q):
 _single_chunk_scores = jax.jit(_single_chunk_scores_impl)
 
 
+@jax.named_scope("proxy_score_chunk")
 def _proxy_chunk_scores_impl(params, block, zq_t):
     """block: (B, D); zq_t: (latent, Q) of normalized query latents."""
     z = l2_normalize(encoder_apply(params, block))
     return (1.0 + z @ zq_t) * 0.5
 
 
+@jax.named_scope("proxy_score_chunk")
 def _raw_chunk_scores_impl(block, zq_t):
     return (1.0 + l2_normalize(block) @ zq_t) * 0.5
 

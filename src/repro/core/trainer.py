@@ -46,6 +46,7 @@ from repro.core import losses
 from repro.core.encoder import encoder_apply, encoder_init, projector_apply
 from repro.kernels.contrastive import ops as contrastive_ops
 from repro.optimizer import adamw
+from repro.runtime import trace
 
 
 class ProxyTrainResult(NamedTuple):
@@ -134,6 +135,7 @@ _KINDS = {
 }
 
 
+@jax.named_scope("proxy_train_step")
 def _train_core(params, ktrain, e_q, embeds, labels, n_valid, *,
                 cfg: ProxyConfig, opt_cfg: OptimizerConfig, kind: str,
                 bs: int):
@@ -297,6 +299,7 @@ def train_proxy(key, e_q: jnp.ndarray, embeds: jnp.ndarray,
 
 @functools.partial(jax.jit,
                    static_argnames=("cfg", "opt_cfg", "kind", "phase2"))
+@jax.named_scope("proxy_train_step")
 def _train_step(params, opt_state, knoise, e_q, xb, yb, *,
                 cfg: ProxyConfig, opt_cfg: OptimizerConfig, kind: str,
                 phase2: bool):
@@ -344,32 +347,44 @@ def train_proxy_multi(keys, e_qs, samples: Sequence, labels: Sequence,
     batches a standalone ``train_proxy(keys[i], ...)`` call would — the
     vmapped run returns identical params, just without Q separate
     dispatch/compile round-trips.
+
+    Each step is a phase of the caller's ambient span: ``rebalance``
+    (key split, encoder init, minority augmentation), ``pad`` (the
+    padded host batch), ``put`` (its host->device copy, as dispatched)
+    and ``run`` (the program, until the losses are on the host).
     """
     q = len(samples)
     assert q == len(labels) and q == len(keys)
-    params0, kbals, ktrain = _compiled_multi_init(cfg)(
-        jnp.stack([jnp.asarray(k) for k in keys]))
-    balanced = []
-    for i, (s, y) in enumerate(zip(samples, labels)):
-        e_np, y_np = np.asarray(s), np.asarray(y)
-        if cfg.rebalance:
-            e_np, y_np = rebalance(kbals[i], e_np, y_np, cfg)
-        balanced.append((e_np, y_np))
-    pad_to = _bucket(max(e.shape[0] for e, _ in balanced))
-    n_valid = jnp.asarray([e.shape[0] for e, _ in balanced], jnp.int32)
-    embeds_np = np.zeros((q, pad_to, balanced[0][0].shape[1]), np.float32)
-    labels_np = np.zeros((q, pad_to), np.float32)
-    for i, (e, y) in enumerate(balanced):
-        embeds_np[i, :e.shape[0]] = e
-        labels_np[i, :y.shape[0]] = y
-    embeds_d, labels_d = jnp.asarray(embeds_np), jnp.asarray(labels_np)
+    with trace.phase("rebalance"):
+        params0, kbals, ktrain = _compiled_multi_init(cfg)(
+            jnp.stack([jnp.asarray(k) for k in keys]))
+        balanced = []
+        for i, (s, y) in enumerate(zip(samples, labels)):
+            e_np, y_np = np.asarray(s), np.asarray(y)
+            if cfg.rebalance:
+                e_np, y_np = rebalance(kbals[i], e_np, y_np, cfg)
+            balanced.append((e_np, y_np))
+    with trace.phase("pad"):
+        pad_to = _bucket(max(e.shape[0] for e, _ in balanced))
+        embeds_np = np.zeros((q, pad_to, balanced[0][0].shape[1]),
+                             np.float32)
+        labels_np = np.zeros((q, pad_to), np.float32)
+        for i, (e, y) in enumerate(balanced):
+            embeds_np[i, :e.shape[0]] = e
+            labels_np[i, :y.shape[0]] = y
+    with trace.phase("put"):
+        n_valid = jnp.asarray([e.shape[0] for e, _ in balanced], jnp.int32)
+        embeds_d, labels_d = jnp.asarray(embeds_np), jnp.asarray(labels_np)
+        e_qs = jnp.asarray(e_qs)
     opt_cfg = _proxy_opt_cfg(cfg)
-    e_qs = jnp.asarray(e_qs)
 
     fn = _compiled_trainer(cfg, opt_cfg, "two_phase", cfg.batch_size,
                            multi=True, donate=_donate())
-    params, l1, l2 = fn(params0, ktrain, e_qs, embeds_d, labels_d, n_valid)
-    return ProxyTrainResultMulti(params, np.asarray(l1), np.asarray(l2))
+    with trace.phase("run"):
+        params, l1, l2 = fn(params0, ktrain, e_qs, embeds_d, labels_d,
+                            n_valid)
+        l1, l2 = np.asarray(l1), np.asarray(l2)
+    return ProxyTrainResultMulti(params, l1, l2)
 
 
 def train_proxy_variant(key, e_q, embeds, labels, cfg: ProxyConfig,

@@ -617,13 +617,15 @@ class ScaleDocEngine:
             for leaf in jobs:
                 oracle = self._session_oracle(leaf.oracle)
                 calls0 = oracle.calls
-                n_train = min(max(int(self.proxy_cfg.train_fraction * n),
-                                  16), n)
-                train_idx = self._train_rng(seed, leaf).choice(
-                    n, size=n_train, replace=False)
-                keys.append(self._train_key(seed, leaf))
-                samples.append(self.store.get(train_idx))
-                labels.append(oracle.label(train_idx))
+                with trace_mod.phase("sample"):
+                    n_train = min(max(int(self.proxy_cfg.train_fraction
+                                          * n), 16), n)
+                    train_idx = self._train_rng(seed, leaf).choice(
+                        n, size=n_train, replace=False)
+                    keys.append(self._train_key(seed, leaf))
+                    samples.append(self.store.get(train_idx))
+                with trace_mod.phase("label"):
+                    labels.append(oracle.label(train_idx))
                 info[leaf.key] = (oracle.calls - calls0, False)
             # batched mode groups up to TRAIN_BATCH_PAD leaves per
             # dispatch; sequential mode dispatches one leaf at a time.
@@ -668,22 +670,26 @@ class ScaleDocEngine:
         """Train up to TRAIN_BATCH_PAD leaves through the one canonical
         program shape: real jobs padded with inert dummies so every
         dispatch compiles (and tiles) identically. Dummy slots cost
-        device FLOPs, never oracle labels, and are sliced off."""
+        device FLOPs, never oracle labels, and are sliced off. Building
+        the dummies is a ``pad`` phase of the ambient span and splitting
+        the trained stack a ``run`` phase (``train_proxy_multi`` records
+        the rest)."""
         k = len(keys)
         if k > TRAIN_BATCH_PAD:
             raise ValueError(f"at most {TRAIN_BATCH_PAD} jobs per "
                              f"training dispatch, got {k}")
         n_train, dim = samples[0].shape
         npad = TRAIN_BATCH_PAD - k
-        keys = list(keys) + [jax.random.PRNGKey(0)] * npad
-        e_qs = list(e_qs) + [np.zeros(dim, np.float32)] * npad
-        samples = list(samples) + [np.zeros((n_train, dim),
-                                            np.float32)] * npad
-        # mixed dummy labels keep the padded slots' loss well-posed
-        labels = list(labels) + [np.arange(n_train) % 2 == 0] * npad
-        res = train_proxy_multi(keys, np.stack(e_qs), samples, labels,
-                                self.proxy_cfg)
-        return unstack_params(res.params)[:k]
+        with trace_mod.phase("pad"):
+            keys = list(keys) + [jax.random.PRNGKey(0)] * npad
+            e_qs = np.stack(list(e_qs) + [np.zeros(dim, np.float32)] * npad)
+            samples = list(samples) + [np.zeros((n_train, dim),
+                                                np.float32)] * npad
+            # mixed dummy labels keep the padded slots' loss well-posed
+            labels = list(labels) + [np.arange(n_train) % 2 == 0] * npad
+        res = train_proxy_multi(keys, e_qs, samples, labels, self.proxy_cfg)
+        with trace_mod.phase("run"):
+            return unstack_params(res.params)[:k]
 
     def _train_leaf_local(self, leaf: SemanticPredicate, seed: int,
                           n: int, info: Dict[str, tuple]) -> Dict:
@@ -692,13 +698,16 @@ class ScaleDocEngine:
         hence bitwise the same params the dead owner would have built."""
         oracle = self._session_oracle(leaf.oracle)
         calls0 = oracle.calls
-        n_train = min(max(int(self.proxy_cfg.train_fraction * n), 16), n)
-        idx = self._train_rng(seed, leaf).choice(n, size=n_train,
-                                                 replace=False)
-        y = oracle.label(idx)
+        with trace_mod.phase("sample"):
+            n_train = min(max(int(self.proxy_cfg.train_fraction * n), 16),
+                          n)
+            idx = self._train_rng(seed, leaf).choice(n, size=n_train,
+                                                     replace=False)
+            sample = self.store.get(idx)
+        with trace_mod.phase("label"):
+            y = oracle.label(idx)
         params = self._train_padded(
-            [self._train_key(seed, leaf)], [leaf.e_q],
-            [self.store.get(idx)], [y])[0]
+            [self._train_key(seed, leaf)], [leaf.e_q], [sample], [y])[0]
         info[leaf.key] = (oracle.calls - calls0, False)
         return params
 
@@ -918,7 +927,11 @@ class ScaleDocEngine:
         carries the per-doc decision mechanism (PROXY_ACCEPT /
         PROXY_REJECT for threshold auto-decisions, ORACLE for band
         labels bought now, CACHED_LABEL for band labels resolved from
-        calibration samples or the shared label cache)."""
+        calibration samples or the shared label cache).
+
+        Its steps are phases of the ambient ``decide`` span:
+        ``threshold``, ``known`` (the calibration labels), ``need`` (the
+        band scan), ``label`` (peek and purchase) and ``merge``."""
         if art.labels_full is not None:
             # whole-strategy artifact: decisions were materialized
             # eagerly at build time — to this session they are cache
@@ -927,26 +940,31 @@ class ScaleDocEngine:
                     np.zeros(len(pending), bool), 0,
                     np.full(len(pending), trace_mod.CACHED_LABEL,
                             np.int8))
-        s = art.scores[pending]
-        labels = s > art.r
-        ambiguous = ~(labels | (s < art.l))
-        mech = np.where(labels, trace_mod.PROXY_ACCEPT,
-                        trace_mod.PROXY_REJECT).astype(np.int8)
-        mech[ambiguous] = trace_mod.CACHED_LABEL
-        known = {int(i): bool(y) for i, y in zip(art.sample_idx,
-                                                 art.sample_labels)}
-        amb_local = np.nonzero(ambiguous)[0]
-        need = np.array([i for i in amb_local
-                         if int(pending[i]) not in known], np.int64)
+        with trace_mod.phase("threshold"):
+            s = art.scores[pending]
+            labels = s > art.r
+            ambiguous = ~(labels | (s < art.l))
+            mech = np.where(labels, trace_mod.PROXY_ACCEPT,
+                            trace_mod.PROXY_REJECT).astype(np.int8)
+            mech[ambiguous] = trace_mod.CACHED_LABEL
+        with trace_mod.phase("known"):
+            known = {int(i): bool(y) for i, y in zip(art.sample_idx,
+                                                     art.sample_labels)}
+        with trace_mod.phase("need"):
+            amb_local = np.nonzero(ambiguous)[0]
+            need = np.array([i for i in amb_local
+                             if int(pending[i]) not in known], np.int64)
         if len(need):
-            # classify before labeling: label() fills the cache, so the
-            # oracle-vs-cached split must be observed first
-            mech[need] = self._peek_mech(oracle, pending[need])
-            labels[need] = np.asarray(oracle.label(pending[need]), bool)
-        for i in amb_local:
-            g = int(pending[i])
-            if g in known:
-                labels[i] = known[g]
+            with trace_mod.phase("label"):
+                # classify before labeling: label() fills the cache, so
+                # the oracle-vs-cached split must be observed first
+                mech[need] = self._peek_mech(oracle, pending[need])
+                labels[need] = np.asarray(oracle.label(pending[need]), bool)
+        with trace_mod.phase("merge"):
+            for i in amb_local:
+                g = int(pending[i])
+                if g in known:
+                    labels[i] = known[g]
         return labels, ambiguous, int(len(need)), mech
 
     # -- degraded-mode resolution ----------------------------------------
@@ -1418,8 +1436,12 @@ class ScaleDocEngine:
                             continue
                         oracle = self._session_oracle(leaf.oracle)
                         c0 = oracle.calls
-                        dec, _, online, dmech = self._decide_pending(
-                            arts[leaf.key], oracle, need)
+                        with self._tracer.span("decide", kind="cascade",
+                                               leaf=leaf.name,
+                                               pending=len(need)) as dspan:
+                            dec, _, online, dmech = self._decide_pending(
+                                arts[leaf.key], oracle, need)
+                            dspan.set(oracle_calls=online)
                         vals[need] = np.asarray(dec, bool).astype(np.int8)
                         last_mech[need] = dmech
                         last_writer[need] = oi

@@ -126,9 +126,12 @@ class PrefetchThread:
     to ``__init__`` after ``depth``), pushing items via ``_put`` and
     returning early when it reports the consumer is gone. The scoring
     ``_Prefetcher`` and the ingest batch feeder share this lifecycle.
+    Each wait on the queue is a ``wait_phase`` phase of the consumer's
+    ambient span.
     """
 
     _DONE = object()
+    wait_phase = "stall"
 
     def __init__(self, depth: int, *args):
         self._queue: "queue.Queue" = queue.Queue(maxsize=max(depth, 1))
@@ -161,9 +164,10 @@ class PrefetchThread:
     def __iter__(self):
         try:
             while True:
-                t0 = time.perf_counter()
-                item = self._queue.get()
-                self.stall_seconds += time.perf_counter() - t0
+                with trace_mod.phase(self.wait_phase):
+                    t0 = time.perf_counter()
+                    item = self._queue.get()
+                    self.stall_seconds += time.perf_counter() - t0
                 if item is self._DONE:
                     return
                 if isinstance(item, BaseException):
@@ -309,17 +313,19 @@ class ScoringExecutor:
         tiles = nbytes = 0
         compute_s = 0.0
         for start, rows, tile_bytes, dev in pre:
-            tc = time.perf_counter()
-            if sharded:
-                s = self._sharded_fn("single")(params, dev, z_q) \
-                    if params is not None else \
-                    self._sharded_fn("raw_multi")(dev, z_q[:, None])[:, 0]
-            elif params is None:
-                s = _raw_chunk_scores(dev, z_q[:, None])[:, 0]
-            else:
-                s = _single_chunk_scores(params, dev, z_q)
-            out[start:start + rows] = np.asarray(s, np.float32)[:rows]
-            compute_s += time.perf_counter() - tc
+            with trace_mod.phase("sync"):
+                tc = time.perf_counter()
+                if sharded:
+                    s = self._sharded_fn("single")(params, dev, z_q) \
+                        if params is not None else \
+                        self._sharded_fn("raw_multi")(dev,
+                                                      z_q[:, None])[:, 0]
+                elif params is None:
+                    s = _raw_chunk_scores(dev, z_q[:, None])[:, 0]
+                else:
+                    s = _single_chunk_scores(params, dev, z_q)
+                out[start:start + rows] = np.asarray(s, np.float32)[:rows]
+                compute_s += time.perf_counter() - tc
             tiles += 1
             nbytes += tile_bytes
         stats = ScoringStats(
@@ -330,10 +336,10 @@ class ScoringExecutor:
             devices=self._mesh_size if sharded else 1,
             paths=("shard",) if sharded else ("jnp",))
         # ambient annotation: lands on the enclosing "score" span (the
-        # engine opens one per scoring pass); no-op outside a trace
-        trace_mod.annotate(tiles=tiles, bytes_streamed=nbytes,
-                           io_seconds=round(pre.io_seconds, 6),
-                           stall_seconds=round(pre.stall_seconds, 6))
+        # engine opens one per scoring pass); no-op outside a trace. The
+        # prefetch thread's read-and-copy time has no phase on this
+        # thread, so it rides as an attribute
+        trace_mod.annotate(io_seconds=round(pre.io_seconds, 6))
         return out, stats
 
     def score_multi(self, jobs: Sequence[Tuple[Optional[Dict], np.ndarray]],
@@ -366,29 +372,30 @@ class ScoringExecutor:
         compute_s = 0.0
         paths = set()
         for start, rows, tile_bytes, dev in pre:
-            tc = time.perf_counter()
-            for (params, cols), zq in zip(groups, zq_stacks):
-                if self.use_kernel and params is not None:
-                    from repro.kernels.fused_scoring import ops as sops
-                    s = sops.score_tile_multi(params, zq, dev,
-                                              interpret=self.interpret)
-                    paths.add("fused")
-                elif sharded:
-                    if params is None:
-                        s = self._sharded_fn("raw_multi")(dev, zq.T)
+            with trace_mod.phase("sync"):
+                tc = time.perf_counter()
+                for (params, cols), zq in zip(groups, zq_stacks):
+                    if self.use_kernel and params is not None:
+                        from repro.kernels.fused_scoring import ops as sops
+                        s = sops.score_tile_multi(params, zq, dev,
+                                                  interpret=self.interpret)
+                        paths.add("fused")
+                    elif sharded:
+                        if params is None:
+                            s = self._sharded_fn("raw_multi")(dev, zq.T)
+                        else:
+                            s = self._sharded_fn("proxy_multi")(
+                                params, dev, zq.T)
+                        paths.add("shard")
+                    elif params is None:
+                        s = _raw_chunk_scores(dev, zq.T)
+                        paths.add("jnp")
                     else:
-                        s = self._sharded_fn("proxy_multi")(params, dev,
-                                                            zq.T)
-                    paths.add("shard")
-                elif params is None:
-                    s = _raw_chunk_scores(dev, zq.T)
-                    paths.add("jnp")
-                else:
-                    s = _proxy_chunk_scores(params, dev, zq.T)
-                    paths.add("jnp")
-                out[start:start + rows, np.asarray(cols)] = \
-                    np.asarray(s, np.float32)[:rows]
-            compute_s += time.perf_counter() - tc
+                        s = _proxy_chunk_scores(params, dev, zq.T)
+                        paths.add("jnp")
+                    out[start:start + rows, np.asarray(cols)] = \
+                        np.asarray(s, np.float32)[:rows]
+                compute_s += time.perf_counter() - tc
             tiles += 1
             nbytes += tile_bytes
         stats = ScoringStats(

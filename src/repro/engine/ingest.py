@@ -64,6 +64,7 @@ from jax.sharding import Mesh, NamedSharding
 from repro import checkpoint as ckpt
 from repro.engine.executor import PrefetchThread
 from repro.engine.store import MemmapStore, StoreWriter
+from repro.runtime import trace as trace_mod
 from repro.runtime.serve_loop import EmbeddingService
 from repro.sharding.rules import RuleSet
 
@@ -205,6 +206,8 @@ class _BatchFeeder(PrefetchThread):
     index only, which is what makes interrupted-and-resumed ingestion
     bit-identical."""
 
+    wait_phase = "feed"
+
     def __init__(self, docs_tokens, start_batch: int, n_docs: int,
                  batch_size: int, pad_width_to: int, depth: int, put_fn):
         super().__init__(depth, docs_tokens, start_batch, n_docs,
@@ -254,6 +257,12 @@ class Ingestor:
                           per distinct document length.
     checkpoint_every_commits: job-counter marker cadence through
                           ``repro.checkpoint`` (0 disables markers).
+    tracer:               records an ``ingest.batch`` span per batch
+                          (phases ``feed``: waiting on the feeder,
+                          ``embed``: dispatch until the rows are on the
+                          host, ``append``) and an ``ingest.durable``
+                          span per commit (phases ``commit``: fsyncs
+                          and manifest swap, ``marker``).
     """
 
     def __init__(self, service: EmbeddingService, *,
@@ -262,7 +271,8 @@ class Ingestor:
                  prefetch_depth: int = DEFAULT_PREFETCH_DEPTH,
                  pad_width_to: int = 16,
                  checkpoint_every_commits: int = 1,
-                 checkpoint_keep: int = 3):
+                 checkpoint_keep: int = 3,
+                 tracer: trace_mod.Tracer = trace_mod.NULL_TRACER):
         if commit_every_batches < 1:
             raise ValueError("commit_every_batches must be >= 1")
         self.service = service
@@ -272,6 +282,7 @@ class Ingestor:
         self.pad_width_to = pad_width_to
         self.checkpoint_every_commits = checkpoint_every_commits
         self.checkpoint_keep = checkpoint_keep
+        self.tracer = tracer
         if self._mesh_size > 1 and service.batch_size % self._mesh_size:
             raise ValueError(
                 f"batch_size={service.batch_size} must divide evenly over "
@@ -380,15 +391,28 @@ class Ingestor:
                               self.pad_width_to, self.prefetch_depth,
                               self._put_fn())
         appended = 0
+        batches = iter(feeder)
         try:
-            for b_idx, n_valid, pad, toks, dev in feeder:
-                tc = time.perf_counter()
-                emb = np.asarray(self.service.embed_batch(dev), np.float32)
-                stats.compute_seconds += time.perf_counter() - tc
-                take = min(n_valid, cap - appended)
-                tw = time.perf_counter()
-                writer.append(emb[:take])
-                stats.write_seconds += time.perf_counter() - tw
+            while True:
+                with self.tracer.span("ingest.batch",
+                                      kind="ingest") as bspan:
+                    item = next(batches, None)      # a "feed" phase
+                    if item is None:
+                        break
+                    b_idx, n_valid, pad, toks, dev = item
+                    # the stats' timers sit inside the phases, so they
+                    # read the same with tracing on or off
+                    with bspan.phase("embed"):
+                        tc = time.perf_counter()
+                        emb = np.asarray(self.service.embed_batch(dev),
+                                         np.float32)
+                        stats.compute_seconds += time.perf_counter() - tc
+                    take = min(n_valid, cap - appended)
+                    with bspan.phase("append"):
+                        tw = time.perf_counter()
+                        writer.append(emb[:take])
+                        stats.write_seconds += time.perf_counter() - tw
+                    bspan.set(batch=b_idx, docs=take)
                 appended += take
                 stats.docs += take
                 stats.batches += 1
@@ -401,6 +425,7 @@ class Ingestor:
                 if appended >= cap:
                     break
         finally:
+            batches.close()
             interrupted = start + appended < n
             if not interrupted:             # ran to the end: durable tail
                 self._commit(writer, stats, ckpt_dir, prior, fp, t0,
@@ -417,27 +442,31 @@ class Ingestor:
     def _commit(self, writer: StoreWriter, stats: IngestStats,
                 ckpt_dir: str, prior: IngestStats, fingerprint: Dict,
                 t0: float, final: bool = False) -> None:
-        tw = time.perf_counter()
-        before = writer.rows
-        rows = writer.commit()
-        stats.write_seconds += time.perf_counter() - tw
-        if rows > before:
-            stats.commits += 1
-        elif not final:
-            return
-        cadence = self.checkpoint_every_commits
-        # cadence counts absolute job commits, so it does not reset on
-        # every resumed run
-        job_commits = prior.commits + stats.commits
-        if (final and rows == before
-                and ckpt.latest_step(ckpt_dir) == rows):
-            return      # the last in-loop commit already marked this row
-        if cadence and (final or (rows > before
-                                  and job_commits % cadence == 0)):
-            stats.wall_seconds = time.perf_counter() - t0
-            job = dataclasses.replace(prior).merge(stats)
-            self._save_marker(ckpt_dir, rows, job, fingerprint)
-            stats.checkpoints += 1
+        with self.tracer.span("ingest.durable", kind="ingest") as span:
+            before = writer.rows
+            with span.phase("commit"):
+                tw = time.perf_counter()
+                rows = writer.commit()
+                stats.write_seconds += time.perf_counter() - tw
+            span.set(rows=rows)
+            if rows > before:
+                stats.commits += 1
+            elif not final:
+                return
+            cadence = self.checkpoint_every_commits
+            # cadence counts absolute job commits, so it does not reset
+            # on every resumed run
+            job_commits = prior.commits + stats.commits
+            if (final and rows == before
+                    and ckpt.latest_step(ckpt_dir) == rows):
+                return  # the last in-loop commit already marked this row
+            if cadence and (final or (rows > before
+                                      and job_commits % cadence == 0)):
+                stats.wall_seconds = time.perf_counter() - t0
+                job = dataclasses.replace(prior).merge(stats)
+                with span.phase("marker"):
+                    self._save_marker(ckpt_dir, rows, job, fingerprint)
+                stats.checkpoints += 1
 
 
 def build_index(service: EmbeddingService, docs_tokens, directory, *,

@@ -50,6 +50,7 @@ class EmbeddingService:
 
         model = self.model
 
+        @jax.named_scope("backbone_forward")
         def embed_batch(params, tokens):
             # teacher-forced forward; pool pre-logits hidden states.
             x = model.embed_inputs(params, tokens)

@@ -20,6 +20,20 @@ through:
   whatever span is current *without holding a tracer reference*; this
   is how deep layers (``ResilientOracle`` retries, executor passes)
   report into the session's tree with zero plumbing.
+* phases — ``span.phase(name)`` (or the ambient ``phase(name)``) times
+  one step inside a span without opening a child span, so the parent's
+  self time is unchanged. Each phase is ``[name, start, end, cpu_s]`` in
+  ``attrs["phases"]``: ``start``/``end`` on ``time.perf_counter()``,
+  ``cpu_s`` the calling thread's CPU seconds (``time.thread_time()``).
+  Every span also records its own thread-CPU seconds as
+  ``attrs["cpu_s"]``, so wall minus CPU shows a thread that waited (on
+  I/O, a device, or the interpreter lock) rather than computed.
+* compiles — the first enabled ``Tracer`` registers one process-wide
+  ``jax.monitoring`` listener that records each backend compile as a
+  ``compile`` phase, and each jaxpr trace and MLIR lowering as a
+  ``lower`` phase, on the compiling thread's ambient span (their
+  ``cpu_s`` is None: the listener sees only the wall duration). A
+  compile outside any span is counted in ``snapshot()``.
 * ``ProvenanceMap`` — the per-document decision provenance a
   ``filter()`` call emits: for every doc, which class of mechanism
   decided it (proxy threshold, oracle purchase, cached label, top-k
@@ -30,9 +44,11 @@ through:
 
 Disabled-path contract: a ``Tracer(enabled=False)`` (or the shared
 ``NULL_TRACER``) returns one preallocated no-op span from every
-``span()`` call — no allocation, no clock read, no lock — so tracing
-gates to near-zero overhead when off, and tracing on/off can never
-change decisions (nothing here touches an RNG stream or an oracle).
+``span()`` call, and that span's ``phase()`` — like the ambient
+``phase()`` with no ambient span — returns one preallocated no-op
+phase: no allocation, no clock read, no lock. Tracing gates to
+near-zero overhead when off, and tracing on/off can never change
+decisions (nothing here touches an RNG stream or an oracle).
 """
 from __future__ import annotations
 
@@ -40,6 +56,7 @@ import dataclasses
 import threading
 import time
 import uuid
+import weakref
 from collections import deque
 from typing import Dict, List, Optional, Sequence, Tuple
 
@@ -48,7 +65,8 @@ import numpy as np
 __all__ = [
     "SpanContext", "Span", "Tracer", "NULL_TRACER",
     "make_traceparent", "parse_traceparent",
-    "current_span", "current_ctx", "annotate", "add_event",
+    "current_span", "current_ctx", "annotate", "add_event", "phase",
+    "NOOP_PHASE",
     "span_tree", "format_span_tree",
     "PROVENANCE_CLASSES", "PROXY_ACCEPT", "PROXY_REJECT", "ORACLE",
     "CACHED_LABEL", "TOPK_SKIP", "PROXY_FALLBACK", "SHORT_CIRCUIT",
@@ -149,6 +167,53 @@ def add_event(name: str, **attrs) -> None:
         span.event(name, **attrs)
 
 
+def phase(name: str):
+    """Time a step of the current ambient span as one of its phases
+    (``with trace.phase("pad"): ...``); the shared no-op phase without
+    an ambient span."""
+    span = current_span()
+    return span.phase(name) if span is not None else NOOP_PHASE
+
+
+# --------------------------------------------------------------------------
+# phases
+# --------------------------------------------------------------------------
+
+class _Phase:
+    """One timed step inside a span, appended to its phases on exit."""
+
+    __slots__ = ("span", "name", "start", "cpu0")
+
+    def __init__(self, span: "Span", name: str):
+        self.span = span
+        self.name = name
+
+    def __enter__(self) -> "_Phase":
+        self.cpu0 = time.thread_time()
+        self.start = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        end = time.perf_counter()
+        self.span.add_phase(self.name, self.start, end,
+                            time.thread_time() - self.cpu0)
+
+
+class _NoopPhase:
+    """The disabled-path phase: enter and exit do nothing."""
+
+    __slots__ = ()
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc) -> None:
+        pass
+
+
+NOOP_PHASE = _NoopPhase()
+
+
 # --------------------------------------------------------------------------
 # spans
 # --------------------------------------------------------------------------
@@ -160,7 +225,7 @@ class Span:
 
     __slots__ = ("tracer", "name", "trace_id", "span_id", "parent_id",
                  "start", "end_time", "attrs", "events", "links",
-                 "thread", "_ended", "_pushed")
+                 "thread", "_ended", "_pushed", "_cpu0", "_ident")
 
     def __init__(self, tracer: "Tracer", name: str,
                  parent: Optional[SpanContext], trace_id: Optional[str],
@@ -180,6 +245,8 @@ class Span:
         self.events: List[Dict] = []
         self.links: List[SpanContext] = []
         self.thread = threading.current_thread().name
+        self._ident = threading.get_ident()
+        self._cpu0 = time.thread_time()
         self._ended = False
         self._pushed = False
 
@@ -203,11 +270,23 @@ class Span:
             self.links.append(ctx)
         return self
 
+    def phase(self, name: str) -> _Phase:
+        """Context manager timing one step of this span (see the module
+        docstring); phases are attributes, not child spans."""
+        return _Phase(self, name)
+
+    def add_phase(self, name: str, start: float, end: float,
+                  cpu_s: Optional[float]) -> None:
+        self.attrs.setdefault("phases", []).append([name, start, end, cpu_s])
+
     def end(self) -> None:
         if self._ended:
             return
         self._ended = True
         self.end_time = time.perf_counter()
+        # thread CPU is per thread: a span ended elsewhere has no reading
+        if threading.get_ident() == self._ident:
+            self.attrs["cpu_s"] = time.thread_time() - self._cpu0
         self.tracer._record(self)
 
     # -- context manager --------------------------------------------------
@@ -231,11 +310,14 @@ class Span:
 
     def to_dict(self) -> Dict:
         end = self.end_time if self.end_time is not None else self.start
+        attrs = dict(self.attrs)
+        if "phases" in attrs:
+            attrs["phases"] = [list(p) for p in attrs["phases"]]
         return {"name": self.name, "trace_id": self.trace_id,
                 "span_id": self.span_id, "parent_id": self.parent_id,
                 "start": self.start, "end": end,
                 "duration": end - self.start, "thread": self.thread,
-                "attrs": dict(self.attrs),
+                "attrs": attrs,
                 "events": [dict(e) for e in self.events],
                 "links": [{"trace_id": c.trace_id, "span_id": c.span_id}
                           for c in self.links]}
@@ -260,6 +342,9 @@ class _NoopSpan:
     def link(self, ctx):
         return self
 
+    def phase(self, name: str):
+        return NOOP_PHASE
+
     def end(self) -> None:
         pass
 
@@ -273,6 +358,51 @@ class _NoopSpan:
 NOOP_SPAN = _NoopSpan()
 
 _AMBIENT = object()     # sentinel: "parent = whatever span is current"
+
+
+# --------------------------------------------------------------------------
+# compiles as phases
+# --------------------------------------------------------------------------
+
+# jax.monitoring duration events -> the phase each becomes
+COMPILE_EVENTS = {
+    "/jax/core/compile/backend_compile_duration": "compile",
+    "/jax/core/compile/jaxpr_trace_duration": "lower",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+}
+_compile_tracers: "weakref.WeakSet[Tracer]" = weakref.WeakSet()
+_compile_listener_lock = threading.Lock()
+_compile_listener_on = False
+
+
+def _on_compile_duration(event: str, duration_secs: float, **_) -> None:
+    name = COMPILE_EVENTS.get(event)
+    if name is None:
+        return
+    span = current_span()
+    if span is not None:
+        end = time.perf_counter()
+        span.add_phase(name, end - duration_secs, end, None)
+    elif name == "compile":
+        for tracer in list(_compile_tracers):
+            tracer._count_compile()
+
+
+def _watch_compiles(tracer: "Tracer") -> None:
+    """Register the process-wide compile listener once (JAX imported
+    lazily: the module itself needs only the stdlib and numpy)."""
+    global _compile_listener_on
+    _compile_tracers.add(tracer)
+    with _compile_listener_lock:
+        if _compile_listener_on:
+            return
+        try:
+            import jax.monitoring
+        except ImportError:
+            return
+        jax.monitoring.register_event_duration_secs_listener(
+            _on_compile_duration)
+        _compile_listener_on = True
 
 
 # --------------------------------------------------------------------------
@@ -295,6 +425,9 @@ class Tracer:
         self._lock = threading.Lock()
         self._ring: "deque[Dict]" = deque(maxlen=self.capacity)
         self._recorded = 0
+        self._compiles_outside = 0
+        if enabled:
+            _watch_compiles(self)
 
     def span(self, name: str, *, parent=_AMBIENT,
              trace_id: Optional[str] = None, **attrs):
@@ -317,6 +450,10 @@ class Tracer:
             self._ring.append(span.to_dict())
             self._recorded += 1
 
+    def _count_compile(self) -> None:
+        with self._lock:
+            self._compiles_outside += 1
+
     # -- queryable products ----------------------------------------------
 
     def spans(self, trace_id: Optional[str] = None,
@@ -336,27 +473,39 @@ class Tracer:
         spans = self.spans(trace_id, limit)
         with self._lock:
             recorded, retained = self._recorded, len(self._ring)
+            compiles = self._compiles_outside
         return {"enabled": self.enabled, "capacity": self.capacity,
                 "recorded": recorded, "retained": retained,
-                "dropped": recorded - retained, "spans": spans}
+                "dropped": recorded - retained,
+                "compiles_outside_spans": compiles, "spans": spans}
 
     def chrome_trace(self, trace_id: Optional[str] = None) -> Dict:
         """Chrome-trace / Perfetto JSON (load via chrome://tracing or
         ui.perfetto.dev). Complete ``X`` events with microsecond
-        timestamps off the monotonic clock; span events become ``i``
-        instants on the same track."""
+        timestamps off the monotonic clock; a span's phases become ``X``
+        slices nested inside it, in start order, and its events ``i``
+        instants, on the same track."""
         events = []
         threads: Dict[str, int] = {}
         for s in self.spans(trace_id):
             tid = threads.setdefault(s["thread"], len(threads) + 1)
+            attrs = dict(s["attrs"])
+            phases = attrs.pop("phases", [])
             args = {"trace_id": s["trace_id"], "span_id": s["span_id"],
-                    "parent_id": s["parent_id"], **s["attrs"]}
+                    "parent_id": s["parent_id"], **attrs}
             if s["links"]:
                 args["links"] = s["links"]
             events.append({"name": s["name"], "cat": "scaledoc",
                            "ph": "X", "ts": s["start"] * 1e6,
                            "dur": s["duration"] * 1e6,
                            "pid": 1, "tid": tid, "args": args})
+            for name, start, end, cpu_s in sorted(phases,
+                                                  key=lambda p: p[1]):
+                events.append({"name": name, "cat": "scaledoc.phase",
+                               "ph": "X", "ts": start * 1e6,
+                               "dur": (end - start) * 1e6,
+                               "pid": 1, "tid": tid,
+                               "args": {"cpu_s": cpu_s}})
             for ev in s["events"]:
                 events.append({"name": ev["name"], "cat": "scaledoc",
                                "ph": "i", "ts": ev["t"] * 1e6,
@@ -369,6 +518,7 @@ class Tracer:
         with self._lock:
             self._ring.clear()
             self._recorded = 0
+            self._compiles_outside = 0
 
 
 NULL_TRACER = Tracer(enabled=False, capacity=1)

@@ -313,3 +313,40 @@ def test_ingest_stats_accounting(service, docs, tmp_path):
     assert 0.0 <= s.overlap_fraction <= 1.0
     merged = dataclasses.replace(s).merge(s)
     assert merged.docs == 2 * N_DOCS
+
+
+def test_traced_ingest_spans_batches_and_commits(service, docs, tmp_path):
+    """A traced run records one ``ingest.batch`` span per batch and one
+    ``ingest.durable`` span per commit call, each with its phases, and
+    writes the same bytes as an untraced run."""
+    from repro.runtime.trace import Tracer
+
+    class Counting(Ingestor):
+        commit_calls = 0
+
+        def _commit(self, *a, **kw):
+            Counting.commit_calls += 1
+            super()._commit(*a, **kw)
+
+    plain = Ingestor(service, commit_every_batches=2).ingest(
+        docs, tmp_path / "plain")
+    tracer = Tracer()
+    traced = Counting(service, commit_every_batches=2,
+                      tracer=tracer).ingest(docs, tmp_path / "traced")
+    assert _bin_bytes(tmp_path / "traced") == _bin_bytes(tmp_path / "plain")
+    assert traced.stats.batches == plain.stats.batches == N_DOCS // BATCH
+    spans = tracer.spans()
+    batches = [s for s in spans if s["name"] == "ingest.batch"]
+    durable = [s for s in spans if s["name"] == "ingest.durable"]
+    assert len(batches) == traced.stats.batches
+    assert len(durable) == Counting.commit_calls >= traced.stats.commits
+    assert [s["attrs"]["batch"] for s in batches] == list(
+        range(N_DOCS // BATCH))
+    for s in batches:
+        steps = [p[0] for p in s["attrs"]["phases"]
+                 if p[0] not in ("compile", "lower")]
+        assert steps == ["feed", "embed", "append"]
+    assert all("commit" in {p[0] for p in s["attrs"]["phases"]}
+               for s in durable)
+    assert any("marker" in {p[0] for p in s["attrs"]["phases"]}
+               for s in durable)

@@ -169,6 +169,147 @@ def test_chrome_trace_export_shape():
             assert e["dur"] >= 0 and "ts" in e and "name" in e
 
 
+# -- phases, thread CPU and compiles -----------------------------------------
+
+def test_phases_nest_inside_their_span_in_order_with_cpu():
+    tracer = Tracer()
+    with tracer.span("outer") as outer:
+        with outer.phase("pad"):
+            sum(range(20000))
+        with trace_mod.phase("run"):          # ambient: lands on outer
+            sum(range(20000))
+    rec = tracer.spans()[0]
+    assert [p[0] for p in rec["attrs"]["phases"]] == ["pad", "run"]
+    assert rec["attrs"]["cpu_s"] >= 0
+    events = tracer.chrome_trace()["traceEvents"]
+    span = next(e for e in events if e["name"] == "outer")
+    assert "phases" not in span["args"]
+    phases = [e for e in events if e["cat"] == "scaledoc.phase"]
+    assert [e["name"] for e in phases] == ["pad", "run"]
+    eps = 1e-3                                  # microseconds of rounding
+    for e in phases:
+        assert e["ph"] == "X" and e["tid"] == span["tid"]
+        assert span["ts"] <= e["ts"]
+        assert e["ts"] + e["dur"] <= span["ts"] + span["dur"] + eps
+        assert e["args"]["cpu_s"] >= 0
+    assert phases[0]["ts"] + phases[0]["dur"] <= phases[1]["ts"] + eps
+
+
+def test_disabled_phases_are_one_singleton_and_read_no_clock(monkeypatch):
+    import threading
+    import time
+    me, reads = threading.get_ident(), []
+
+    def counted(real, name):
+        def clock():
+            if threading.get_ident() == me:    # other threads' reads aside
+                reads.append(name)
+            return real()
+        return clock
+    for name in ("perf_counter", "thread_time"):
+        monkeypatch.setattr(time, name, counted(getattr(time, name), name))
+    assert trace_mod.current_span() is None
+    bare = trace_mod.phase("stall")
+    with trace_mod.NULL_TRACER.span("score") as span:
+        held = span.phase("sync")
+        ambient = trace_mod.phase("sync")
+        with held, ambient, bare:
+            pass
+    assert bare is held is ambient is trace_mod.NOOP_PHASE
+    assert reads == []
+    with Tracer().span("on") as on, on.phase("p"):   # the counter counts
+        pass
+    assert {"perf_counter", "thread_time"} <= set(reads)
+
+
+def test_a_new_jit_shape_leaves_a_compile_phase():
+    import jax
+    import jax.numpy as jnp
+    tracer = Tracer()
+    with tracer.span("work"):
+        jax.jit(lambda x: x * 3 + 1)(jnp.ones(23)).block_until_ready()
+    phases = tracer.spans()[0]["attrs"]["phases"]
+    compiles = [p for p in phases if p[0] == "compile"]
+    assert compiles and all(p[2] >= p[1] and p[3] is None
+                            for p in compiles)
+    assert "lower" in {p[0] for p in phases}
+    # under a disabled tracer there is no span to hold a phase: the
+    # compile is counted by the enabled tracers, and recorded nowhere
+    off = Tracer(enabled=False)
+    before = tracer.snapshot()["compiles_outside_spans"]
+    with off.span("work"):
+        jax.jit(lambda x: x * 5 - 1)(jnp.ones(29)).block_until_ready()
+    assert off.spans() == [] and off.snapshot()["recorded"] == 0
+    assert tracer.snapshot()["compiles_outside_spans"] > before
+    assert len(tracer.spans()) == 1
+
+
+def test_each_program_carries_its_named_scope():
+    """The proxy training step, the scoring chunk programs and the
+    backbone forward keep a stable name in their lowered text."""
+    import jax
+    import jax.numpy as jnp
+    from repro.config.base import ModelConfig
+    from repro.core import scoring, trainer
+    from repro.core.encoder import encoder_init
+    from repro.models import build_model
+    from repro.runtime.serve_loop import EmbeddingService
+    pcfg = ProxyConfig(embed_dim=8, hidden_dim=16, latent_dim=8,
+                       proj_dim=4, phase1_steps=2, phase2_steps=2,
+                       batch_size=4)
+    params = encoder_init(jax.random.PRNGKey(0), pcfg)
+    block, z = jnp.ones((16, 8)), jnp.ones((8,))
+    fn = trainer._compiled_trainer(pcfg, trainer._proxy_opt_cfg(pcfg),
+                                   "two_phase", 4, multi=False,
+                                   donate=False)
+    mcfg = ModelConfig(name="scope-test", num_layers=1, d_model=16,
+                       num_heads=2, num_kv_heads=2, d_ff=32, vocab_size=32,
+                       dtype="float32", remat="none")
+    service = EmbeddingService(mcfg, build_model(mcfg).init(
+        jax.random.PRNGKey(0)), batch_size=2)
+    lowered = {
+        "proxy_train_step": fn.lower(params, jax.random.PRNGKey(1), z,
+                                     block, jnp.ones(16), 16),
+        "proxy_score_chunk": scoring._single_chunk_scores.lower(
+            params, block, jnp.ones(pcfg.latent_dim)),
+        "backbone_forward": service._embed.lower(
+            service.params, jnp.ones((2, 8), jnp.int32)),
+    }
+    for scope, low in lowered.items():
+        assert scope in low.as_text(debug_info=True), scope
+    for fn in (scoring._proxy_chunk_scores, scoring._raw_chunk_scores):
+        args = ((params, block, jnp.ones((pcfg.latent_dim, 2)))
+                if fn is scoring._proxy_chunk_scores
+                else (block, jnp.ones((8, 2))))
+        assert "proxy_score_chunk" in fn.lower(*args).as_text(
+            debug_info=True)
+
+
+def test_traced_filter_records_every_phase_and_decides_the_same(corpus,
+                                                                cfgs):
+    _, preds = _workload(corpus)
+    untraced = _engine(corpus, cfgs).filter(preds[1], seed=1)
+    _, preds = _workload(corpus)            # fresh oracles
+    engine = _engine(corpus, cfgs)
+    engine._tracer = Tracer()
+    traced = engine.filter(preds[1], seed=1)
+    np.testing.assert_array_equal(untraced.mask, traced.mask)
+    for a, b in zip(untraced.leaf_reports, traced.leaf_reports):
+        np.testing.assert_array_equal(a.scores, b.scores)
+    want = {"train": {"sample", "label", "rebalance", "pad", "put", "run"},
+            "score": {"stall", "sync"},
+            "decide": {"threshold", "known", "need", "label", "merge"}}
+    spans = engine._tracer.spans()
+    for name, phases in want.items():
+        got = {p[0] for s in spans if s["name"] == name
+               for p in s["attrs"].get("phases", ())}
+        assert phases <= got, (name, got)
+    assert all("cpu_s" in s["attrs"] for s in spans)
+    train = next(s for s in spans if s["name"] == "train")
+    for _, start, end, _ in train["attrs"]["phases"]:
+        assert train["start"] <= start <= end <= train["end"]
+
+
 # -- provenance map ----------------------------------------------------------
 
 
