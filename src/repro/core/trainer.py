@@ -25,6 +25,8 @@ stacked (e_q, sample, labels) sets so a compound predicate's leaves all
 train in one program; ragged samples are zero-padded to a shared bucket
 and a per-leaf ``n_valid`` bounds the batch sampler, which makes padding
 invisible to the math — multi results are identical to Q single calls.
+The padded batch is built on the device: the host copies only the rows
+that exist, in fixed-size blocks, into a device buffer of zeros.
 
 Batch indices are drawn per step as ``randint(fold_in(key, t), (bs,), 0,
 n_valid)`` (uniform with replacement). The pre-scan per-step host loop
@@ -87,10 +89,12 @@ def rebalance(key, embeds: np.ndarray, labels: np.ndarray,
         # degenerate sample: nothing to mirror — caller handles
         return embeds, labels
     minority = 1 if n_pos < n_neg else 0
-    src = embeds[labels == minority]
-    need = int(cfg.rebalance_min_frac * n) - len(src)
+    need = int(cfg.rebalance_min_frac * n) - min(n_pos, n_neg)
     if need <= 0:
         return embeds, labels
+    # the sample is read on the host only when it is augmented
+    embeds = np.asarray(embeds)
+    src = embeds[labels == minority]
     rng = np.random.default_rng(_key_seed(key))
     idx = rng.integers(0, len(src), size=need)
     noise = rng.normal(0.0, cfg.rebalance_noise, size=(need, embeds.shape[1]))
@@ -221,14 +225,72 @@ def _bucket(n: int) -> int:
     return m
 
 
+# Rows one host->device block carries (32 MB at 4096-d f32). Blocks are
+# one shape per padded batch, so the batch costs one writer program per
+# (Q, pad_to, D), whatever the lanes' row counts.
+ROW_BLOCK = 2048
+
+
+@functools.partial(jax.jit, donate_argnums=0)
+def _write_rows(batch, block, lane, row):
+    """Write one (B, D) block of a lane's rows into the (Q, pad_to, D)
+    device batch at ``[lane, row:row + B]``; the donated batch is
+    updated in place."""
+    return jax.lax.dynamic_update_slice(batch, block[None], (lane, row, 0))
+
+
+def _row_blocks(samples: Sequence, pad_to: int
+                ) -> List[Tuple[int, int, np.ndarray]]:
+    """``(lane, row, block)`` f32 host blocks that cover each sample's
+    rows.
+
+    A block is ``min(ROW_BLOCK, pad_to)`` rows, which divides ``pad_to``
+    (both are powers of two). A lane's last block ends at its last row,
+    so it rewrites rows the block before it wrote, with the same values,
+    instead of being padded; only a lane shorter than one block is
+    zero-padded, here. A ``None`` sample has no rows and no blocks."""
+    blk = min(ROW_BLOCK, pad_to)
+    out = []
+    for lane, e in enumerate(samples):
+        if e is None:
+            continue
+        e = np.asarray(e, np.float32)
+        n = e.shape[0]
+        if n < blk:
+            block = np.zeros((blk, e.shape[1]), np.float32)
+            block[:n] = e
+            out.append((lane, 0, block))
+            continue
+        for row in range(0, n, blk):
+            row = min(row, n - blk)
+            out.append((lane, row, e[row:row + blk]))
+    return out
+
+
+def _device_batch(blocks, shape: Tuple[int, int, int]
+                  ) -> Tuple[jax.Array, int]:
+    """The zero-padded f32 ``shape`` batch, made on the device: zeros,
+    with each host block written in. Returns it and the bytes the blocks
+    copied to the device.
+
+    Each write is waited for before the next block is sent, so the
+    device holds the batch and one block in flight, not every block of
+    the sample; the host has nothing else to do before the program
+    runs, so the waits cost only a dispatch each."""
+    batch = jnp.zeros(shape, jnp.float32)
+    for lane, row, block in blocks:
+        batch = _write_rows(batch, block, np.int32(lane), np.int32(row))
+        batch.block_until_ready()
+    return batch, sum(block.nbytes for _, _, block in blocks)
+
+
 def _pad_sample(embeds: np.ndarray, labels: np.ndarray,
                 pad_to: int) -> Tuple[jnp.ndarray, jnp.ndarray, int]:
-    n = embeds.shape[0]
+    n, d = embeds.shape
+    batch, _ = _device_batch(_row_blocks([embeds], pad_to), (1, pad_to, d))
     if n < pad_to:
-        embeds = np.concatenate(
-            [embeds, np.zeros((pad_to - n, embeds.shape[1]), embeds.dtype)])
         labels = np.concatenate([labels, np.zeros(pad_to - n, labels.dtype)])
-    return (jnp.asarray(embeds), jnp.asarray(labels.astype(np.float32)),
+    return (batch.reshape(pad_to, d), jnp.asarray(labels.astype(np.float32)),
             n)
 
 
@@ -341,41 +403,48 @@ def train_proxy_multi(keys, e_qs, samples: Sequence, labels: Sequence,
     """Train Q independent proxies in ONE compiled program.
 
     keys: Q PRNG keys; e_qs: (Q, D) query embeddings; samples[i]:
-    (n_i, D) labeled embeddings; labels[i]: (n_i,) {0,1}. Ragged sample
-    sizes are zero-padded to a shared bucket; a per-proxy ``n_valid``
-    bounds the on-device batch sampler, so each lane draws exactly the
-    batches a standalone ``train_proxy(keys[i], ...)`` call would — the
-    vmapped run returns identical params, just without Q separate
-    dispatch/compile round-trips.
+    (n_i, D) labeled embeddings, or None for a lane with no rows (an
+    inert filler lane: it trains on len(labels[i]) zero rows, and is not
+    rebalanced); labels[i]: (n_i,) {0,1}. Ragged sample sizes are
+    zero-padded to a shared bucket; a per-proxy ``n_valid`` bounds the
+    on-device batch sampler, so each lane draws exactly the batches a
+    standalone ``train_proxy(keys[i], ...)`` call would — the vmapped
+    run returns identical params, just without Q separate
+    dispatch/compile round-trips. The padded batch is made on the
+    device (``_device_batch``): only the lanes' rows cross from the
+    host, and their bytes are added to the ambient span's
+    ``h2d_bytes``.
 
     Each step is a phase of the caller's ambient span: ``rebalance``
-    (key split, encoder init, minority augmentation), ``pad`` (the
-    padded host batch), ``put`` (its host->device copy, as dispatched)
-    and ``run`` (the program, until the losses are on the host).
+    (key split, encoder init, minority augmentation), ``pad`` (the host
+    blocks and padded labels), ``put`` (the blocks' copies and the
+    device batch, as dispatched) and ``run`` (the program, until the
+    losses are on the host).
     """
     q = len(samples)
     assert q == len(labels) and q == len(keys)
     with trace.phase("rebalance"):
         params0, kbals, ktrain = _compiled_multi_init(cfg)(
             jnp.stack([jnp.asarray(k) for k in keys]))
-        balanced = []
+        lanes = []
         for i, (s, y) in enumerate(zip(samples, labels)):
-            e_np, y_np = np.asarray(s), np.asarray(y)
-            if cfg.rebalance:
-                e_np, y_np = rebalance(kbals[i], e_np, y_np, cfg)
-            balanced.append((e_np, y_np))
+            y = np.asarray(y)
+            if cfg.rebalance and s is not None:
+                s, y = rebalance(kbals[i], s, y, cfg)
+            lanes.append((s, y))
     with trace.phase("pad"):
-        pad_to = _bucket(max(e.shape[0] for e, _ in balanced))
-        embeds_np = np.zeros((q, pad_to, balanced[0][0].shape[1]),
-                             np.float32)
+        n_valid = [len(y) if s is None else len(s) for s, y in lanes]
+        pad_to = _bucket(max(n_valid))
+        blocks = _row_blocks([s for s, _ in lanes], pad_to)
         labels_np = np.zeros((q, pad_to), np.float32)
-        for i, (e, y) in enumerate(balanced):
-            embeds_np[i, :e.shape[0]] = e
-            labels_np[i, :y.shape[0]] = y
+        for i, (_, y) in enumerate(lanes):
+            labels_np[i, :len(y)] = y
     with trace.phase("put"):
-        n_valid = jnp.asarray([e.shape[0] for e, _ in balanced], jnp.int32)
-        embeds_d, labels_d = jnp.asarray(embeds_np), jnp.asarray(labels_np)
         e_qs = jnp.asarray(e_qs)
+        embeds_d, sent = _device_batch(blocks, (q, pad_to, e_qs.shape[1]))
+        trace.tally(h2d_bytes=sent)
+        labels_d = jnp.asarray(labels_np)
+        n_valid = jnp.asarray(n_valid, jnp.int32)
     opt_cfg = _proxy_opt_cfg(cfg)
 
     fn = _compiled_trainer(cfg, opt_cfg, "two_phase", cfg.batch_size,

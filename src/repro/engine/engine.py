@@ -21,7 +21,8 @@ state across queries:
     front, and groups of them train per compiled device program
     (``train_proxy_multi``: the scanned trainer vmapped over leaves —
     mirroring ``score_collection_multi`` on the scoring side). Every
-    dispatch is padded to the fixed ``TRAIN_BATCH_PAD`` shape, which
+    dispatch is padded to the fixed ``TRAIN_BATCH_PAD`` shape (filler
+    lanes and padded rows are zeros made on the device), which
     makes trained params a pure function of ``(leaf, seed)`` — the
     property cross-session proxy sharing (repro.engine.optimizer) and
     batched-vs-sequential parity both rest on. Training on
@@ -669,11 +670,13 @@ class ScaleDocEngine:
     def _train_padded(self, keys, e_qs, samples, labels) -> List[Dict]:
         """Train up to TRAIN_BATCH_PAD leaves through the one canonical
         program shape: real jobs padded with inert dummies so every
-        dispatch compiles (and tiles) identically. Dummy slots cost
-        device FLOPs, never oracle labels, and are sliced off. Building
-        the dummies is a ``pad`` phase of the ambient span and splitting
-        the trained stack a ``run`` phase (``train_proxy_multi`` records
-        the rest)."""
+        dispatch compiles (and tiles) identically. A dummy lane has keys,
+        a zero query and mixed labels but no sample: the trainer leaves
+        its rows at zero on the device, so dummies cost device FLOPs,
+        never host memory, copies or oracle labels, and are sliced off.
+        Building the dummies is a ``pad`` phase of the ambient span and
+        splitting the trained stack a ``run`` phase
+        (``train_proxy_multi`` records the rest)."""
         k = len(keys)
         if k > TRAIN_BATCH_PAD:
             raise ValueError(f"at most {TRAIN_BATCH_PAD} jobs per "
@@ -683,8 +686,7 @@ class ScaleDocEngine:
         with trace_mod.phase("pad"):
             keys = list(keys) + [jax.random.PRNGKey(0)] * npad
             e_qs = np.stack(list(e_qs) + [np.zeros(dim, np.float32)] * npad)
-            samples = list(samples) + [np.zeros((n_train, dim),
-                                                np.float32)] * npad
+            samples = list(samples) + [None] * npad
             # mixed dummy labels keep the padded slots' loss well-posed
             labels = list(labels) + [np.arange(n_train) % 2 == 0] * npad
         res = train_proxy_multi(keys, e_qs, samples, labels, self.proxy_cfg)

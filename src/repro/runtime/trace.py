@@ -16,10 +16,11 @@ through:
   ``parse_traceparent`` carry a (trace_id, span_id) pair over HTTP in
   the W3C header shape, so a gateway request, the server session it
   admits, and every engine/broker span under it share one rooted tree.
-* ambient annotation — ``annotate()`` / ``add_event()`` attach data to
-  whatever span is current *without holding a tracer reference*; this
-  is how deep layers (``ResilientOracle`` retries, executor passes)
-  report into the session's tree with zero plumbing.
+* ambient annotation — ``annotate()`` / ``tally()`` / ``add_event()``
+  attach data to whatever span is current *without holding a tracer
+  reference*; this is how deep layers (``ResilientOracle`` retries,
+  executor passes, the trainer's ``h2d_bytes``) report into the
+  session's tree with zero plumbing.
 * phases — ``span.phase(name)`` (or the ambient ``phase(name)``) times
   one step inside a span without opening a child span, so the parent's
   self time is unchanged. Each phase is ``[name, start, end, cpu_s]`` in
@@ -65,7 +66,8 @@ import numpy as np
 __all__ = [
     "SpanContext", "Span", "Tracer", "NULL_TRACER",
     "make_traceparent", "parse_traceparent",
-    "current_span", "current_ctx", "annotate", "add_event", "phase",
+    "current_span", "current_ctx", "annotate", "tally", "add_event",
+    "phase",
     "NOOP_PHASE",
     "span_tree", "format_span_tree",
     "PROVENANCE_CLASSES", "PROXY_ACCEPT", "PROXY_REJECT", "ORACLE",
@@ -158,6 +160,15 @@ def annotate(**attrs) -> None:
     span = current_span()
     if span is not None:
         span.set(**attrs)
+
+
+def tally(**counts) -> None:
+    """Add to numeric attributes of the current ambient span (no-op
+    without one): a count that several calls inside one span share."""
+    span = current_span()
+    if span is not None:
+        for name, n in counts.items():
+            span.attrs[name] = span.attrs.get(name, 0) + n
 
 
 def add_event(name: str, **attrs) -> None:

@@ -115,3 +115,18 @@ def test_vmapped_proxy_trainer_compiles_with_kernel(one_chip):
             jax.ShapeDtypeStruct((q,), jnp.int32))
     args = jax.tree.map(lambda a: _spec(one_chip, a.shape, a.dtype), args)
     _assert_kernel(fn.lower(*args).compile())
+
+
+def test_device_batch_row_writer_compiles_in_place(one_chip):
+    """The trainer's device batch: one host block written into the
+    cold cell's (TRAIN_BATCH_PAD, 16384, 4096) f32 buffer, which the
+    program updates in place (its output aliases the donated input)."""
+    from repro.core import trainer
+    from repro.engine.engine import TRAIN_BATCH_PAD
+
+    q, n, d = TRAIN_BATCH_PAD, 16384, 4096
+    compiled = trainer._write_rows.lower(
+        _spec(one_chip, (q, n, d)), _spec(one_chip, (trainer.ROW_BLOCK, d)),
+        _spec(one_chip, (), jnp.int32),
+        _spec(one_chip, (), jnp.int32)).compile()
+    assert "input_output_alias" in compiled.as_text()

@@ -195,6 +195,19 @@ def test_phases_nest_inside_their_span_in_order_with_cpu():
     assert phases[0]["ts"] + phases[0]["dur"] <= phases[1]["ts"] + eps
 
 
+def test_tally_adds_to_the_ambient_span_and_nothing_without_one():
+    trace_mod.tally(h2d_bytes=5)                   # no span: no-op
+    tracer = Tracer()
+    with tracer.span("train"):
+        trace_mod.tally(h2d_bytes=3)
+        trace_mod.tally(h2d_bytes=4, blocks=1)
+    with Tracer(enabled=False).span("train"):
+        trace_mod.tally(h2d_bytes=9)
+    assert tracer.spans()[0]["attrs"]["h2d_bytes"] == 7
+    assert tracer.spans()[0]["attrs"]["blocks"] == 1
+    assert len(tracer.spans()) == 1
+
+
 def test_disabled_phases_are_one_singleton_and_read_no_clock(monkeypatch):
     import threading
     import time
@@ -308,6 +321,43 @@ def test_traced_filter_records_every_phase_and_decides_the_same(corpus,
     train = next(s for s in spans if s["name"] == "train")
     for _, start, end, _ in train["attrs"]["phases"]:
         assert train["start"] <= start <= end <= train["end"]
+
+
+def test_traced_train_counts_only_the_real_rows_it_copies(corpus, cfgs,
+                                                         monkeypatch):
+    """The ``train`` span's ``h2d_bytes`` is the two real lanes' rows,
+    plus at most one block a lane, and no filler lane: at most half of
+    the four-lane padded stack. Blocks smaller than the sample change no
+    decision."""
+    from repro.core import trainer
+    from repro.engine.engine import TRAIN_BATCH_PAD
+    _, preds = _workload(corpus)
+    untraced = _engine(corpus, cfgs).filter(preds[1], seed=1)
+    block = 16
+    monkeypatch.setattr(trainer, "ROW_BLOCK", block)
+    rows = []
+
+    def spy(*args):
+        out = real_rebalance(*args)
+        rows.append(len(out[0]))
+        return out
+
+    real_rebalance = trainer.rebalance
+    monkeypatch.setattr(trainer, "rebalance", spy)
+    _, preds = _workload(corpus)
+    engine = _engine(corpus, cfgs)
+    engine._tracer = Tracer()
+    traced = engine.filter(preds[1], seed=1)
+    np.testing.assert_array_equal(untraced.mask, traced.mask)
+    for a, b in zip(untraced.leaf_reports, traced.leaf_reports):
+        np.testing.assert_array_equal(a.scores, b.scores)
+    train = next(s for s in engine._tracer.spans() if s["name"] == "train")
+    row_bytes = DIM * 4
+    assert len(rows) == 2
+    real = sum(rows) * row_bytes
+    assert real <= train["attrs"]["h2d_bytes"] <= real + 2 * block * row_bytes
+    stack = TRAIN_BATCH_PAD * trainer._bucket(max(rows)) * row_bytes
+    assert train["attrs"]["h2d_bytes"] <= stack // 2
 
 
 # -- provenance map ----------------------------------------------------------

@@ -1,6 +1,7 @@
 """Compiled proxy trainer: scan-vs-step-loop parity, vmapped multi-leaf
-training vs per-leaf training, typed-key rebalancing, and variant
-dedup onto the scanned core."""
+training vs per-leaf training, the device-built padded batch against the
+host-built one, typed-key rebalancing, and variant dedup onto the
+scanned core."""
 import dataclasses
 
 import jax
@@ -9,6 +10,7 @@ import numpy as np
 import pytest
 
 from repro.config.base import ProxyConfig
+from repro.core import trainer
 from repro.core.trainer import (ProxyTrainResult, mlp_classifier_scores,
                                 rebalance, train_proxy, train_proxy_multi,
                                 train_proxy_variant, unstack_params)
@@ -95,6 +97,88 @@ def test_multi_matches_single(cfg):
         np.testing.assert_allclose(multi.phase2_losses[i],
                                    single.phase2_losses,
                                    rtol=1e-4, atol=1e-5)
+
+
+def _engine_lanes(rng, sizes, pos_rates):
+    """Real lanes of the given sizes, then filler lanes up to four as the
+    engine passes them: no sample, mixed labels of the first lane's size."""
+    keys = [jax.random.fold_in(jax.random.PRNGKey(17), i) for i in range(4)]
+    e_qs = np.zeros((4, DIM), np.float32)
+    e_qs[:len(sizes)] = rng.normal(size=(len(sizes), DIM))
+    samples = [rng.normal(size=(n, DIM)).astype(np.float32) for n in sizes]
+    labels = [(rng.random(n) < p).astype(np.float32)
+              for n, p in zip(sizes, pos_rates)]
+    dummies = 4 - len(sizes)
+    return (keys, e_qs, samples + [None] * dummies,
+            labels + [np.arange(sizes[0]) % 2 == 0] * dummies)
+
+
+def _host_stack_multi(keys, e_qs, samples, labels, cfg):
+    """``train_proxy_multi`` as it ran on a zero-padded stack built on
+    the host, filler lanes as zero rows: the reference for the device
+    batch."""
+    params0, kbals, ktrain = trainer._compiled_multi_init(cfg)(
+        jnp.stack(keys))
+    lanes = []
+    for i, (s, y) in enumerate(zip(samples, labels)):
+        s = np.zeros((len(y), DIM), np.float32) if s is None else s
+        lanes.append(rebalance(kbals[i], s, np.asarray(y), cfg))
+    pad_to = trainer._bucket(max(len(e) for e, _ in lanes))
+    embeds = np.zeros((len(lanes), pad_to, DIM), np.float32)
+    labs = np.zeros((len(lanes), pad_to), np.float32)
+    for i, (e, y) in enumerate(lanes):
+        embeds[i, :len(e)] = e
+        labs[i, :len(y)] = y
+    fn = trainer._compiled_trainer(cfg, trainer._proxy_opt_cfg(cfg),
+                                   "two_phase", cfg.batch_size, multi=True,
+                                   donate=trainer._donate())
+    params, l1, l2 = fn(params0, ktrain, jnp.asarray(e_qs),
+                        jnp.asarray(embeds), jnp.asarray(labs),
+                        jnp.asarray([len(e) for e, _ in lanes], jnp.int32))
+    return params, np.asarray(l1), np.asarray(l2), lanes
+
+
+def test_device_batch_is_bitwise_the_host_stack(cfg):
+    """A plain lane, a rebalanced lane whose rows are no multiple of the
+    block, and two filler lanes: the device-built batch trains bitwise
+    what the host-built zero-padded stack trained."""
+    rng = np.random.default_rng(4)
+    keys, e_qs, samples, labels = _engine_lanes(rng, [2500, 2300],
+                                                [0.4, 0.05])
+    params, l1, l2, lanes = _host_stack_multi(keys, e_qs, samples, labels,
+                                              cfg)
+    rows = [len(e) for e, _ in lanes]
+    assert rows[1] > 2300 and rows[1] % trainer.ROW_BLOCK  # augmented
+    assert rows[0] % trainer.ROW_BLOCK
+    res = train_proxy_multi(keys, e_qs, samples, labels, cfg)
+    _tree_allclose(res.params, params, rtol=0, atol=0)
+    np.testing.assert_array_equal(res.phase1_losses, l1)
+    np.testing.assert_array_equal(res.phase2_losses, l2)
+    assert res.phase1_losses.shape == (4, cfg.phase1_steps)
+
+
+def test_row_counts_in_one_bucket_compile_nothing_new(cfg):
+    """Lanes of other sizes in the same bucket, one of them rebalanced,
+    reuse every program of the first call: nothing compiles or lowers."""
+    from repro.runtime.trace import Tracer
+    rng = np.random.default_rng(5)
+    tracer = Tracer()
+    first = _engine_lanes(rng, [2500, 3000], [0.4, 0.5])
+    second = _engine_lanes(rng, [2300, 3900], [0.05, 0.4])
+    with tracer.span("first"):
+        train_proxy_multi(*first, cfg)
+    outside = tracer.snapshot()["compiles_outside_spans"]
+    with tracer.span("second"):
+        res = train_proxy_multi(*second, cfg)
+    span = tracer.spans()[-1]
+    builds = [p for p in span["attrs"]["phases"]
+              if p[0] in ("compile", "lower")]
+    assert builds == []
+    assert tracer.snapshot()["compiles_outside_spans"] == outside
+    # two blocks for each real lane (2760 rebalanced rows, 3900), none
+    # for the filler lanes
+    assert span["attrs"]["h2d_bytes"] == 2 * 2 * trainer.ROW_BLOCK * DIM * 4
+    assert np.isfinite(res.phase2_losses[:2]).all()
 
 
 def test_rebalance_accepts_typed_and_legacy_keys(cfg, sample):
